@@ -7,6 +7,7 @@ import sys
 
 from benchmarks import (fig6_flicker, fig8_atmolight, kernels_bench,
                         roofline_report, table1_throughput)
+from repro.core import env
 
 SUITES = {
     "table1": table1_throughput.rows,
@@ -27,6 +28,7 @@ SUITES = {
 
 
 def main() -> None:
+    env.enable_compile_cache()
     wanted = [a for a in sys.argv[1:] if a in SUITES] or list(SUITES)
     print("name,us_per_call,derived")
     for key in wanted:

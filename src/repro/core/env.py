@@ -26,6 +26,10 @@ Knobs:
                          entry nor an env override exists (no silent defaults)
   REPRO_BENCH_SMOKE      benchmark drivers use tiny CI shapes when truthy
 
+JAX's own ``JAX_COMPILATION_CACHE_DIR`` is read here too
+(:func:`compile_cache_dir`): the entry points turn the persistent compile
+cache on through :func:`enable_compile_cache`, never at import.
+
 ``snapshot()`` / ``restore()`` capture and reinstate the full ``REPRO_*``
 environment for test isolation (monkeypatch-free setup/teardown of
 multi-knob scenarios).
@@ -43,6 +47,8 @@ SUBSTRATES = ("ref", "pallas", "interpret")
 KERNEL_MODES = SUBSTRATES + ("fused", "auto")
 
 _TUNING_DEFAULT_PATH = Path("results") / "kernel_tuning.json"
+# The checkout root (src/repro/core/env.py -> three levels up).
+_CHECKOUT = Path(__file__).resolve().parents[3]
 
 
 def kernel_mode() -> str:
@@ -150,6 +156,28 @@ def bench_smoke() -> bool:
     return bool(os.environ.get("REPRO_BENCH_SMOKE"))
 
 
+def compile_cache_dir() -> Path:
+    """Directory of JAX's persistent compilation cache:
+    ``JAX_COMPILATION_CACHE_DIR`` when set, else ``.jax_cache`` in the
+    checkout. The path is part of the cache key, so it is fixed: never a
+    temporary name, a pid or the time."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR", "")
+    return Path(env) if env else _CHECKOUT / ".jax_cache"
+
+
+def enable_compile_cache() -> Path:
+    """Turn the persistent compilation cache on for an entry point
+    (``chip_smoke.py``, ``launch/serve.py``, ``benchmarks/run.py``) and
+    return its directory. Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX
+    already uses it and nothing is changed; otherwise the cache goes to
+    :func:`compile_cache_dir`. Tests never call this."""
+    import jax
+    path = compile_cache_dir()
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", str(path))
+    return path
+
+
 # ---------------------------------------------------------------------------
 # Test isolation
 # ---------------------------------------------------------------------------
@@ -172,4 +200,4 @@ __all__ = ["SUBSTRATES", "KERNEL_MODES", "kernel_mode", "lane_native",
            "tick_overlap",
            "step_cache_size", "tuning_table_path", "tune_override",
            "tune_device_kind", "tune_require_table", "bench_smoke",
-           "snapshot", "restore"]
+           "compile_cache_dir", "enable_compile_cache", "snapshot", "restore"]

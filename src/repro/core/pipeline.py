@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.core import algorithms as alg
-from repro.core import compat
 from repro.core import env as _env
 from repro.core import spatial
 from repro.core.config import DehazeConfig
@@ -585,7 +584,7 @@ def _make_sharded_step(cfg: DehazeConfig, mesh: jax.sharding.Mesh,
         return DehazeOutput(out.astype(odt), t.astype(odt),
                             a_seq.astype(odt), new_state)
 
-    step = compat.shard_map(
+    step = jax.shard_map(
         lane_local_step if lanes else local_step, mesh=mesh,
         in_specs=(fspec, ispec, state_spec),
         out_specs=DehazeOutput(frames=fspec, transmission=fspec,

@@ -1,8 +1,9 @@
 """Jitted dispatch wrappers for the dehazing kernels.
 
 Every op has three execution paths selected by ``mode``:
-  - ``"ref"``      : pure-jnp oracle (XLA everywhere; default on CPU)
-  - ``"pallas"``   : compiled Pallas TPU kernel (default on TPU)
+  - ``"ref"``      : pure-jnp oracle (XLA; the default on every backend)
+  - ``"pallas"``   : compiled Pallas TPU kernel (opt-in; does not lower
+                     for v5e yet, see ``resolve_mode``)
   - ``"interpret"``: Pallas kernel body interpreted on CPU (tests)
 
 ``"fused"`` is a fourth, *pipeline-level* mode: instead of one launch per
@@ -46,9 +47,21 @@ MODES = _env.KERNEL_MODES
 def resolve_mode(mode: Mode = "auto") -> str:
     """Resolve to an execution substrate: ref | pallas | interpret.
 
+    ``"auto"`` resolves to env ``REPRO_KERNEL_MODE`` if set, else to the
+    XLA substrate (``"ref"``) on every backend, TPU included. The Pallas
+    kernels do not lower for v5e yet: an ahead-of-time compile for a
+    described ``v5e:2x2`` at 8 x 1080 x 1920 uint8 refuses the
+    ``_atmolight_kernel`` output block and the frame-id block of
+    ``_fused_dehaze_dbuf_kernel`` (both break the (8, 128) block rule),
+    while the XLA step compiles. ROADMAP Speed item 2 lists every refusal
+    and the layout work that would fix them.
+
     ``"fused"`` is a pipeline-level mode (it selects *which* ops run, not
-    *how*); here it resolves like "auto": env ``REPRO_KERNEL_MODE`` if it
-    names a substrate, else Pallas on TPU and the XLA oracle elsewhere.
+    *how*); its substrate is env ``REPRO_KERNEL_MODE`` if it names one,
+    else the compiled Pallas megakernel on TPU and the XLA oracle
+    elsewhere. ``"pallas"`` and ``"fused"`` are explicit opt-ins: on a TPU
+    they run the Pallas kernels and fail at lowering, never swapped for
+    the XLA path in silence.
 
     Unknown values — in the argument or in ``REPRO_KERNEL_MODE`` — raise
     ``ValueError`` (validation lives in ``core.env.kernel_mode``). They
@@ -60,14 +73,14 @@ def resolve_mode(mode: Mode = "auto") -> str:
         raise ValueError(
             f"unknown kernel mode {mode!r}; expected one of {sorted(MODES)}")
     env = _env.kernel_mode()
-    default = "pallas" if jax.default_backend() == "tpu" else "ref"
     if env == "auto":                    # explicit "auto" == unset
         env = ""
     m = mode
     if m == "auto":
-        m = env or default
+        m = env or "ref"
     if m == "fused":
-        m = env if env in SUBSTRATES else default
+        m = env if env in SUBSTRATES else (
+            "pallas" if jax.default_backend() == "tpu" else "ref")
     return m
 
 

@@ -111,7 +111,7 @@ def device_kind() -> str:
 
     ``REPRO_TUNE_DEVICE_KIND`` overrides (checked per call — CI validates
     foreign tables this way); the hardware answer
-    (``jax.devices()[0].device_kind``, e.g. ``"cpu"``, ``"TPU v5e"``) is
+    (``jax.devices()[0].device_kind``, e.g. ``"cpu"``, ``"TPU v5 lite"``) is
     cached for the process, since ``get_params`` sits on the eager
     per-batch dispatch path."""
     env = _env.tune_device_kind()

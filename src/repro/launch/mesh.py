@@ -10,8 +10,6 @@ from typing import Optional, Tuple
 
 import jax
 
-from repro.core import compat
-
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     """Single pod: (16, 16) ("data", "model") = 256 chips.
@@ -20,7 +18,8 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     all-reduce crosses pods once per step)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:

@@ -29,7 +29,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core import compat
 from repro.models import common as cm
 from repro.models.common import ParamSpec
 
@@ -321,7 +320,7 @@ def make_moe_ffn(cfg: LMConfig, mesh: Mesh,
         return y.reshape(b, s, dm), aux
 
     e_spec = P("model", None, None) if model_axis else P(None, None, None)
-    return compat.shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(x_spec,
                   P(None, None),        # router replicated
@@ -511,7 +510,7 @@ def make_seqpar_attention(cfg: LMConfig, mesh: Mesh):
 
     kvspec = P(None, "model", None, None)
     rep4 = P(None, None, None, None)
-    return compat.shard_map(
+    return jax.shard_map(
         local_attn, mesh=mesh,
         in_specs=(rep4, rep4, rep4, kvspec, kvspec, P(), P()),
         out_specs=(rep4, kvspec, kvspec),

@@ -63,6 +63,10 @@ def donation_supported() -> bool:
     second bucket; current ones alias. The serving tiers gate the
     overlapped tick path on this, keeping the blocking path as both the
     fallback and the parity oracle.
+
+    On a TPU there is no fallback: the backend donates, so a probe that
+    raises, or a donated input that stays alive, is a fault of the device
+    path and raises instead of quietly turning the overlapped tick off.
     """
     global _donation_supported
     if _donation_supported is not None:
@@ -70,6 +74,7 @@ def donation_supported() -> bool:
     with _probe_lock:
         if _donation_supported is not None:
             return _donation_supported
+        on_tpu = jax.default_backend() == "tpu"
         try:
             f = jax.jit(lambda x: x + 1, donate_argnums=(0,))
             x = jnp.zeros((8,), jnp.float32)
@@ -78,7 +83,13 @@ def donation_supported() -> bool:
                 jax.block_until_ready(f(x))
             supported = bool(x.is_deleted())
         except Exception:
+            if on_tpu:
+                raise
             supported = False
+        if on_tpu and not supported:
+            raise RuntimeError(
+                "buffer donation probe: the TPU backend left a donated "
+                "input alive; the overlapped serve tick cannot run")
         _donation_supported = supported
     return supported
 

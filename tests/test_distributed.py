@@ -19,7 +19,8 @@ def run_child(body: str, devices: int = 8) -> None:
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={devices}"
     """) + textwrap.dedent(body)
-    env = dict(os.environ,
+    # CPU only: a child must never reach for a chip its parent may hold.
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                PYTHONPATH=os.path.abspath(REPO_SRC))
     env.pop("XLA_FLAGS", None)
     proc = subprocess.run([sys.executable, "-c", code], env=env,
@@ -30,11 +31,11 @@ def run_child(body: str, devices: int = 8) -> None:
 def test_sharded_dehaze_matches_single_device():
     run_child("""
         import numpy as np, jax, jax.numpy as jnp
-        from repro.core import compat
         from repro.core import (DehazeConfig, make_dehaze_step,
                                 make_sharded_dehaze_step, init_atmo_state)
         from repro.core.physics import synthesize_haze, transmission_from_depth
-        mesh = compat.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         rng = np.random.default_rng(2)
         B, H, W = 4, 64, 48
         J = jnp.asarray(rng.random((B, H, W, 3), np.float32)) * 0.8
@@ -64,10 +65,10 @@ def test_sharded_dehaze_multihop_halo():
     """Halo larger than the per-shard height -> multi-hop ppermute path."""
     run_child("""
         import numpy as np, jax, jax.numpy as jnp
-        from repro.core import compat
         from repro.core import (DehazeConfig, make_dehaze_step,
                                 make_sharded_dehaze_step, init_atmo_state)
-        mesh = compat.make_mesh((1, 8), ("data", "model"))
+        mesh = jax.make_mesh((1, 8), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         rng = np.random.default_rng(3)
         B, H, W = 2, 64, 32          # 8 rows/shard
         I = jnp.asarray(rng.random((B, H, W, 3), np.float32))
@@ -91,10 +92,10 @@ def test_packed_halo_matches_rgb_halo():
     halo path within dtype tolerance."""
     run_child("""
         import numpy as np, jax, jax.numpy as jnp
-        from repro.core import compat
         from repro.core import (DehazeConfig, make_dehaze_step,
                                 make_sharded_dehaze_step, init_atmo_state)
-        mesh = compat.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         rng = np.random.default_rng(2)
         I = jnp.asarray(rng.random((4, 64, 48, 3), np.float32))
         ids = jnp.arange(4, dtype=jnp.int32)
@@ -123,12 +124,12 @@ def test_sharded_fused_halo_matches_staged_chain():
     run_child("""
         import os
         import numpy as np, jax, jax.numpy as jnp
-        from repro.core import compat
         from repro.core import (DehazeConfig, make_dehaze_step,
                                 make_sharded_dehaze_step, init_atmo_state)
         import repro.kernels.ops as kops
 
-        mesh = compat.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         rng = np.random.default_rng(2)
         I = jnp.asarray(rng.random((4, 64, 48, 3), np.float32))
         ids = jnp.arange(4, dtype=jnp.int32)
@@ -177,10 +178,10 @@ def test_sharded_fused_halo_multihop():
     ppermute) — the extended block is mostly neighbor rows."""
     run_child("""
         import numpy as np, jax, jax.numpy as jnp
-        from repro.core import compat
         from repro.core import (DehazeConfig, make_dehaze_step,
                                 make_sharded_dehaze_step, init_atmo_state)
-        mesh = compat.make_mesh((1, 8), ("data", "model"))
+        mesh = jax.make_mesh((1, 8), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         rng = np.random.default_rng(3)
         I = jnp.asarray(rng.random((2, 64, 32, 3), np.float32))
         ids = jnp.arange(2, dtype=jnp.int32)
@@ -203,7 +204,6 @@ def test_moe_ep_matches_single_device():
     """Expert-parallel all-to-all MoE == single-device execution."""
     run_child("""
         import numpy as np, jax, jax.numpy as jnp
-        from repro.core import compat
         from repro.models import transformer as T
         from repro.models import common as cm
         cfg = T.LMConfig(n_layers=2, d_model=32, n_heads=4, n_kv_heads=2,
@@ -214,7 +214,8 @@ def test_moe_ep_matches_single_device():
         toks = jax.random.randint(jax.random.key(1), (4, 8), 0, 64)
         ref_logits, _ = jax.jit(T.make_forward(cfg))(params, toks)
 
-        mesh = compat.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         fwd = T.make_forward(cfg, mesh, ("data",))
         from jax.sharding import NamedSharding, PartitionSpec as P
         pspecs = cm.param_pspecs(T.lm_param_table(cfg), mesh=mesh)
@@ -235,10 +236,10 @@ def test_ema_state_sync_across_batches_sharded():
     over the data axis (collective state synchronization)."""
     run_child("""
         import numpy as np, jax, jax.numpy as jnp
-        from repro.core import compat
         from repro.core import (DehazeConfig, make_dehaze_step,
                                 make_sharded_dehaze_step, init_atmo_state)
-        mesh = compat.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         rng = np.random.default_rng(5)
         cfg = DehazeConfig(kernel_mode="ref", gf_radius=4, update_period=3)
         step_ref = jax.jit(make_dehaze_step(cfg))
@@ -263,7 +264,6 @@ def test_seqpar_flash_decode_matches_standard():
     full and chunked attention (EXPERIMENTS §Perf / long_500k)."""
     run_child("""
         import numpy as np, jax, jax.numpy as jnp
-        from repro.core import compat
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.models import transformer as T, common as cm
         for chunk in (0, 8):
@@ -277,7 +277,8 @@ def test_seqpar_flash_decode_matches_standard():
             dec = jax.jit(T.make_decode_step(cfg))
             last, cache = pre(params, toks[:, :16])
             ref_lg, ref_cache = dec(params, cache, toks[:, 16:17])
-            mesh = compat.make_mesh((2, 4), ("data", "model"))
+            mesh = jax.make_mesh((2, 4), ("data", "model"),
+                                 axis_types=(jax.sharding.AxisType.Auto,) * 2)
             cfg2 = T.LMConfig(**{**cfg.__dict__, "decode_seq_shard": True})
             dec2 = T.make_decode_step(cfg2, mesh, ("data",))
             spec = {"k": P(None, "data", "model", None, None),
@@ -298,7 +299,6 @@ def test_seq_sharded_lm_forward_matches():
     """LM forward with batch+TP sharding == single device (numerics)."""
     run_child("""
         import numpy as np, jax, jax.numpy as jnp
-        from repro.core import compat
         from repro.models import transformer as T
         from repro.models import common as cm
         from jax.sharding import NamedSharding, PartitionSpec as P
@@ -308,7 +308,8 @@ def test_seq_sharded_lm_forward_matches():
         params = cm.init_params(jax.random.key(0), T.lm_param_table(cfg))
         toks = jax.random.randint(jax.random.key(1), (4, 16), 0, 64)
         ref, _ = jax.jit(T.make_forward(cfg))(params, toks)
-        mesh = compat.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         pspecs = cm.param_pspecs(T.lm_param_table(cfg), mesh=mesh)
         shard = jax.tree.map(lambda p: NamedSharding(mesh, p), pspecs,
                              is_leaf=lambda x: isinstance(x, P))
@@ -353,11 +354,11 @@ def test_lane_sharded_step_matches_per_lane_single_device():
     single-device ``make_dehaze_step`` chains."""
     run_child("""
         import numpy as np, jax, jax.numpy as jnp
-        from repro.core import compat
         from repro.core import (DehazeConfig, PlacementSpec, make_step,
                                 make_dehaze_step, init_atmo_state,
                                 init_atmo_state_lanes, get_lane_state)
-        mesh = compat.make_mesh((2, 2), ("data", "model"))
+        mesh = jax.make_mesh((2, 2), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         rng = np.random.default_rng(7)
         L, B, H, W = 4, 3, 32, 32
         frames = jnp.asarray(rng.random((L, B, H, W, 3), np.float32))

@@ -122,3 +122,16 @@ def test_benchmarks_use_env_module():
     for bench in ("kernels_bench.py", "table1_throughput.py"):
         text = (SRC.parent / "benchmarks" / bench).read_text()
         assert "environ" not in text, f"{bench} bypasses repro.core.env"
+
+
+def test_compile_cache_dir(monkeypatch):
+    """The persistent compile cache lives where JAX_COMPILATION_CACHE_DIR
+    says, else at one fixed, git-ignored directory of the checkout."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/srv/jax-cache")
+    assert env.compile_cache_dir() == Path("/srv/jax-cache")
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    path = env.compile_cache_dir()
+    assert path == SRC.parent / ".jax_cache"
+    assert path == env.compile_cache_dir()          # fixed, not per call
+    ignored = (SRC.parent / ".gitignore").read_text().split()
+    assert ".jax_cache/" in ignored

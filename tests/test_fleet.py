@@ -98,7 +98,11 @@ def _drive_queue(n_streams, n_hosts, lanes, prefs, choices):
     """Replay a random schedule against the shared queue: hosts pop in an
     arbitrary interleaving, admitted streams either finish or get
     preempted-and-requeued (pinned), until the queue drains. Returns the
-    queue for invariant checks."""
+    queue for invariant checks.
+
+    Preemption stops after ``16 * n_streams`` steps, so every schedule
+    drains: a choice sequence that always says "preempt" (hypothesis's
+    first example is all zeros) would otherwise requeue forever."""
     q = _FleetQueue(n_hosts, lanes, lambda sid: prefs[sid])
     for i in range(n_streams):
         q.seed(StreamRequest(f"s{i}", iter(())))
@@ -119,7 +123,8 @@ def _drive_queue(n_streams, n_hosts, lanes, prefs, choices):
             step += 1
             h, req = live.pop(choices(step) % len(live))
             occupied[h] -= 1
-            if choices(step + 1) % 3 == 0:       # preempt: requeue pinned
+            if (choices(step + 1) % 3 == 0       # preempt: requeue pinned
+                    and step < 16 * n_streams):
                 resume = _Resume(None, 0, threading.Event())
                 resume.barrier.set()
                 q.push_requeue(req, resume, pin=h)

@@ -260,7 +260,9 @@ def _run_child(body: str, devices: int = 8) -> None:
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={devices}"
     """) + textwrap.dedent(body)
-    env = dict(os.environ, PYTHONPATH=os.path.abspath(REPO_SRC))
+    # CPU only: a child must never reach for a chip its parent may hold.
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.abspath(REPO_SRC))
     env.pop("XLA_FLAGS", None)
     proc = subprocess.run([sys.executable, "-c", code], env=env,
                           capture_output=True, text=True, timeout=900)
@@ -277,10 +279,10 @@ def test_sharded_parity_matrix(n_h, n_w):
     cell; the (2, 2) mesh adds the corner shards."""
     _run_child(f"""
         import numpy as np, jax, jax.numpy as jnp
-        from repro.core import compat
         from repro.core import (DehazeConfig, make_dehaze_step,
                                 make_sharded_dehaze_step, init_atmo_state)
-        mesh = compat.make_mesh((2, {n_h}, {n_w}), ("data", "model", "width"))
+        mesh = jax.make_mesh((2, {n_h}, {n_w}), ("data", "model", "width"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 3)
         # Tie-stable ramp frames — see _frames() in the parent module.
         rng = np.random.default_rng(2)
         g = (rng.permutation(4 * 32 * 32).reshape(4, 32, 32) + 1.0) / (4096 + 1.0)
@@ -329,10 +331,10 @@ def test_sharded_parity_tie_plateau():
     sort key exists for."""
     _run_child("""
         import numpy as np, jax, jax.numpy as jnp
-        from repro.core import compat
         from repro.core import (DehazeConfig, make_dehaze_step,
                                 make_sharded_dehaze_step, init_atmo_state)
-        mesh = compat.make_mesh((1, 2, 2), ("data", "model", "width"))
+        mesh = jax.make_mesh((1, 2, 2), ("data", "model", "width"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 3)
         rng = np.random.default_rng(7)
         # Quantized frames: large equal-t plateaus across shard boundaries,
         # but per-pixel RGB still varies inside a plateau (the channel mins

@@ -102,6 +102,17 @@ def test_resolve_mode_explicit_arg_still_resolves(monkeypatch):
     assert ops.resolve_mode("fused") in ("ref", "pallas")
 
 
+def test_resolve_mode_auto_is_xla_on_tpu(monkeypatch):
+    """On a TPU ``auto`` resolves to the XLA substrate (the Pallas kernels
+    do not lower for v5e yet), while the explicit opt-ins stay Pallas so
+    they fail loudly at lowering instead of being swapped in silence."""
+    monkeypatch.delenv("REPRO_KERNEL_MODE", raising=False)
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+    assert ops.resolve_mode("auto") == "ref"
+    assert ops.resolve_mode("pallas") == "pallas"
+    assert ops.resolve_mode("fused") == "pallas"
+
+
 # --- spout padding must not advance coherence state --------------------------
 
 def test_spout_padding_tagged_minus_one():
@@ -420,8 +431,8 @@ def test_warm_failure_permanent_raises_on_request():
 
 
 def test_warm_failures_ride_the_serve_report():
-    """`ServeReport.warm_failures` carries the count (the
-    --expect-switches serve path exits nonzero on it)."""
+    """`ServeReport.warm_failures` carries the count (the serve launcher
+    exits nonzero on it)."""
     import dataclasses
 
     from repro.stream.scheduler import ServeReport
@@ -431,3 +442,30 @@ def test_warm_failures_ride_the_serve_report():
     rep = ServeReport(per_stream={}, frames=0, skipped=0, wall_s=0.0,
                       n_lanes=4, ticks=0, warm_failures=2)
     assert rep.warm_failures == 2
+
+
+def test_serve_launcher_fails_on_warm_failures(monkeypatch):
+    """A ladder rung that failed to warm (e.g. a lane batch too large for
+    device memory) makes the serve launcher exit nonzero even when no
+    switches were expected: it is not a warning after which the run
+    exits 0."""
+    import argparse
+
+    from repro.core import DehazeConfig
+    from repro.launch import serve
+    from repro.stream.scheduler import ServeReport
+
+    def fake_serve_many(self, streams, **kw):
+        return ServeReport(per_stream={}, frames=0, skipped=0, wall_s=0.0,
+                           n_lanes=2, ticks=0, warm_failures=1)
+
+    monkeypatch.setattr(serve.ElasticServer, "serve_many", fake_serve_many)
+    args = argparse.Namespace(
+        ramp=False, streams=2, frames=2, io_dtype="float32", lanes=2,
+        batch=2, timeout_ms=20.0, autoscale=False, hosts=1,
+        algorithm="dcp", resolution="tiny", expect_switches=0,
+        expect_spillover=0, expect_overlap=False)
+    cfg = DehazeConfig(algorithm="dcp", kernel_mode="ref")
+    with pytest.raises(SystemExit) as exc:
+        serve._serve_many(args, cfg, 8, 8)
+    assert exc.value.code == 1

@@ -37,6 +37,30 @@ def _cfg(**kw):
     return DehazeConfig(**kw)
 
 
+# --- donation probe -----------------------------------------------------------
+
+@pytest.mark.parametrize("fault", ["raises", "not_donated"])
+def test_donation_probe_raises_on_tpu(monkeypatch, fault):
+    """On a TPU a failing donation probe is a device-path fault: it raises
+    instead of quietly turning the overlapped tick off (off the TPU the
+    same failure still means "no donation" and the blocking path)."""
+    from repro.stream import iobuf
+
+    def fake_jit(fn, donate_argnums=()):
+        if fault == "raises":
+            raise RuntimeError("probe compile failed")
+        return lambda x: x + 1                 # runs, donates nothing
+
+    monkeypatch.setattr(iobuf, "_donation_supported", None)
+    monkeypatch.setattr(iobuf.jax, "jit", fake_jit)
+    monkeypatch.setattr(iobuf.jax, "default_backend", lambda: "cpu")
+    assert iobuf.donation_supported() is False
+    monkeypatch.setattr(iobuf, "_donation_supported", None)
+    monkeypatch.setattr(iobuf.jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError):
+        iobuf.donation_supported()
+
+
 # --- fetch_valid --------------------------------------------------------------
 
 def test_fetch_valid_slices_and_lane_select():
