@@ -192,21 +192,30 @@ def _make_single_step(cfg: DehazeConfig, associative: bool = True):
     t_est = alg.get_transmission_estimator(cfg.algorithm)
     scan = ema_scan_associative if associative else ema_scan
 
+    # Each component and stage runs under a ``jax.named_scope``: the scope
+    # prefixes the HLO ``op_name`` metadata (and nothing else), so a
+    # profiler trace can attribute device time per component.
     def step(frames: jnp.ndarray, frame_ids: jnp.ndarray,
              state: AtmoState) -> DehazeOutput:
         x, odt = _ingest(frames, cfg)
         # Component 1: transmission from the *saved* shared A (paper §3.3).
-        t_raw = t_est(x, state.A, cfg)
+        with jax.named_scope("transmission"):
+            t_raw = t_est(x, state.A, cfg)
         # Component 2: per-frame candidates, then cross-frame normalization.
-        a_new = alg.estimate_atmospheric_light(x, t_raw, cfg)
-        a_seq, new_state = scan(a_new, frame_ids, state,
-                                cfg.update_period, cfg.lam)
-        a_seq = a_seq.astype(x.dtype)
+        with jax.named_scope("atmospheric_light"):
+            a_new = alg.estimate_atmospheric_light(x, t_raw, cfg)
+        with jax.named_scope("normalize"):
+            a_seq, new_state = scan(a_new, frame_ids, state,
+                                    cfg.update_period, cfg.lam)
+            a_seq = a_seq.astype(x.dtype)
         if cfg.recompute_t_with_final_a and cfg.algorithm == "dcp":
-            t_raw = t_est(x, a_seq, cfg)
-        t = alg.refine_transmission(x, t_raw, cfg)
+            with jax.named_scope("transmission"):
+                t_raw = t_est(x, a_seq, cfg)
+        with jax.named_scope("refine"):
+            t = alg.refine_transmission(x, t_raw, cfg)
         # Component 3: haze-free generation.
-        out = alg.generate_haze_free(x, t, a_seq, cfg)
+        with jax.named_scope("recover"):
+            out = alg.generate_haze_free(x, t, a_seq, cfg)
         return DehazeOutput(out.astype(odt), t.astype(odt),
                             a_seq.astype(odt), new_state)
 

@@ -73,7 +73,7 @@ def _wire_hazy(vid, io_dtype: str) -> np.ndarray:
 def _print_tick_io(rep) -> None:
     """One line of tick-I/O accounting (README §Tick I/O & overlap):
     how many ticks took the zero-copy path, the valid-only D2H volume,
-    and where the tick wall went (host staging / device step / deliver)."""
+    and every ``ServeReport.phases`` key (``repro.stream.spans``)."""
     ph = rep.phases or {}
     phase_txt = " ".join(f"{k}={ph[k] * 1e3:.1f}ms" for k in sorted(ph))
     print(f"tick_io: overlap_ticks={rep.overlap_ticks}/{rep.ticks} "

@@ -26,8 +26,8 @@ from __future__ import annotations
 import queue
 import threading
 import time
-from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, Iterable, Optional
+from dataclasses import dataclass
+from typing import Any, Callable, Iterable, Optional
 
 import jax
 import numpy as np
@@ -35,6 +35,7 @@ import numpy as np
 from repro.core.normalize import AtmoState
 from repro.stream.iobuf import fetch_valid
 from repro.stream.monitor import Monitor
+from repro.stream.spans import Phases
 from repro.stream.spout import FrameBatch
 
 
@@ -48,8 +49,6 @@ class DispatchStats:
     overlap_batches: int = 0
     # Bytes fetched device->host by completions (valid-only always).
     d2h_bytes: int = 0
-    # Serve-loop seconds by phase, same keys as ``ServeReport.phases``.
-    phases: Dict[str, float] = field(default_factory=dict)
 
     @property
     def fps(self) -> float:
@@ -63,47 +62,45 @@ class StreamDispatcher:
                  max_in_flight: int = 4,
                  n_workers: int = 1,
                  worker_delay_s: Optional[Callable[[int], float]] = None,
-                 overlap: bool = False,
-                 clock: Callable[[], float] = time.perf_counter):
+                 overlap: bool = False):
         self._step = step
         self._monitor = monitor
         self._sem = threading.Semaphore(max_in_flight)
         self._n_workers = max(1, n_workers)
         self._worker_delay = worker_delay_s
         self._overlap = overlap
-        self._clock = clock
         self._completions: "queue.Queue" = queue.Queue()
         self._stats_lock = threading.Lock()
-        self.stats = DispatchStats(
-            phases={"host_stage_s": 0.0, "device_step_s": 0.0,
-                    "deliver_s": 0.0})
+        # Serve-loop seconds by phase (the ``ServeReport.phases`` keys);
+        # hand it to the Spout that feeds ``run``.
+        self.phases = Phases(self._stats_lock)
+        self.stats = DispatchStats()
 
     def run(self, batches: Iterable[FrameBatch], state: AtmoState) -> AtmoState:
         t0 = time.perf_counter()
         threads = []
         batch_idx = 0
         for fb in batches:
-            t_stage = self._clock()
-            if self._overlap:
-                # Async H2D ahead of the dispatch: the transfer of batch
-                # k+1 overlaps batch k's compute. With a donated step the
-                # device buffer is consumed by the call (out.frames
-                # aliases it when the dtype contract allows), so it is
-                # never reused across batches.
-                frames = jax.device_put(fb.frames)
-            else:
-                frames = fb.frames
-            self._phase("host_stage_s", self._clock() - t_stage)
-            self._sem.acquire()
+            with self.phases.span("stage"):
+                if self._overlap:
+                    # Async H2D ahead of the dispatch: the transfer of
+                    # batch k+1 overlaps batch k's compute. With a donated
+                    # step the device buffer is consumed by the call
+                    # (out.frames aliases it when the dtype contract
+                    # allows), so it is never reused across batches.
+                    frames = jax.device_put(fb.frames)
+                else:
+                    frames = fb.frames
+            with self.phases.span("inflight_wait"):
+                self._sem.acquire()
             # State threading is sequential by construction: the step for
             # batch k is dispatched with the (device-resident, possibly
             # not-yet-computed) state output of batch k-1. JAX's async
             # dispatch pipelines them without blocking the host. With a
             # donated step the old state is consumed by this call — it is
             # dead here anyway (rebound to out.state below).
-            t_step = self._clock()
-            out = self._step(frames, fb.frame_ids, state)
-            self._phase("device_step_s", self._clock() - t_step)
+            with self.phases.span("dispatch"):
+                out = self._step(frames, fb.frame_ids, state)
             state = out.state
             worker = batch_idx % self._n_workers
             th = threading.Thread(
@@ -120,23 +117,17 @@ class StreamDispatcher:
         self.stats.wall_s = time.perf_counter() - t0
         return jax.device_get(state)
 
-    def _phase(self, key: str, dt: float) -> None:
-        with self._stats_lock:
-            self.stats.phases[key] = self.stats.phases.get(key, 0.0) + dt
-
     def _complete(self, fb: FrameBatch, out: Any, worker: int) -> None:
         try:
-            t0 = self._clock()
             # One completion mechanism for both serve paths: valid-only
             # deferred fetch (the old whole-batch np.asarray stalled on —
             # and shipped — the padding tail too).
-            frames = fetch_valid(out.frames, fb.n_valid)
+            frames = fetch_valid(out.frames, fb.n_valid, phases=self.phases)
             if self._worker_delay is not None:
                 time.sleep(self._worker_delay(worker))
             for i in range(fb.n_valid):
                 self._monitor.put(int(fb.frame_ids[i]), frames[i])
             with self._stats_lock:
                 self.stats.d2h_bytes += frames.nbytes
-            self._phase("deliver_s", self._clock() - t0)
         finally:
             self._sem.release()

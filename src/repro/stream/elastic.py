@@ -41,6 +41,7 @@ from repro.stream.monitor import DEADLINE_CLOCK, Monitor
 from repro.stream.scheduler import (MultiServeReport, MultiStreamScheduler,
                                     ServeReport, StreamEntry, StreamReport,
                                     _coerce_request)
+from repro.stream.spans import MONITOR_QUEUE_KEY
 from repro.stream.spout import Spout
 from repro.stream.state import StreamStateStore
 
@@ -200,12 +201,12 @@ class ElasticServer:
         step = _cached_step(self.cfg, donate=True) if overlap else self._step
         start = self.store.cursor(stream_id)
         monitor = Monitor(write, timeout_s=self.timeout_s, start_frame=start)
-        spout = Spout(frames, batch=self.batch, start_frame=start,
-                      stream_id=stream_id)
         dispatcher = StreamDispatcher(
             step, monitor, max_in_flight=self.max_in_flight,
             n_workers=self.n_workers, worker_delay_s=self._worker_delay,
             overlap=overlap)
+        spout = Spout(frames, batch=self.batch, start_frame=start,
+                      stream_id=stream_id, phases=dispatcher.phases)
 
         import threading
         mon_thread = threading.Thread(target=monitor.run, daemon=True)
@@ -216,6 +217,7 @@ class ElasticServer:
         mon_thread.join(timeout=5.0)
         monitor.drain()
         wall = time.perf_counter() - t0
+        dispatcher.phases.add(MONITOR_QUEUE_KEY, monitor.stats.queue_s)
 
         cursor = start + dispatcher.stats.frames
         self.store.update(stream_id, state, cursor)
@@ -228,7 +230,7 @@ class ElasticServer:
             n_lanes=self.n_workers, ticks=dispatcher.stats.batches,
             overlap_ticks=dispatcher.stats.overlap_batches,
             d2h_bytes=dispatcher.stats.d2h_bytes,
-            phases=dict(dispatcher.stats.phases))
+            phases=dispatcher.phases.snapshot())
 
     def serve_many(self, streams: Sequence[StreamEntry],
                    n_lanes: Optional[int] = None,

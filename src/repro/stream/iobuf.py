@@ -49,6 +49,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from repro.stream.spans import Phases
+
 _probe_lock = threading.Lock()
 _donation_supported: Optional[bool] = None
 
@@ -94,10 +96,21 @@ def donation_supported() -> bool:
     return supported
 
 
-def fetch_valid(frames, n_valid: int, lane: Optional[int] = None
-                ) -> np.ndarray:
+def fetch_ready(view, phases: Phases) -> np.ndarray:
+    """Fetch a device array to the host in two spans of ``phases``:
+    ``device_wait`` until the device has computed it, then ``fetch``, the
+    D2H copy and host delinearize of the ready array."""
+    with phases.span("device_wait"):
+        jax.block_until_ready(view)
+    with phases.span("fetch"):
+        return np.asarray(view)
+
+
+def fetch_valid(frames, n_valid: int, lane: Optional[int] = None,
+                phases: Optional[Phases] = None) -> np.ndarray:
     """Valid-only D2H: fetch ``frames[lane, :n_valid]`` (or
-    ``frames[:n_valid]`` when ``lane`` is None) as a host array.
+    ``frames[:n_valid]`` when ``lane`` is None) as a host array, through
+    :func:`fetch_ready` into the serve's ``phases``.
 
     The slice is dispatched on device *before* the blocking fetch, so
     only the requested bytes cross the wire — padding frames (and, per
@@ -106,7 +119,8 @@ def fetch_valid(frames, n_valid: int, lane: Optional[int] = None
     dispatcher.
     """
     view = frames if lane is None else frames[lane]
-    return np.asarray(view[:n_valid])
+    return fetch_ready(view[:n_valid],
+                       phases if phases is not None else Phases())
 
 
 @partial(jax.jit, donate_argnums=(0,))
@@ -221,4 +235,4 @@ def is_overlap_step(step) -> bool:
 
 
 __all__ = ["LaneTickStep", "TickBufferPool", "donation_supported",
-           "fetch_valid", "is_overlap_step"]
+           "fetch_ready", "fetch_valid", "is_overlap_step"]

@@ -30,8 +30,10 @@ DEADLINE_CLOCK: Callable[[], float] = time.monotonic
 class MonitorStats:
     emitted: int = 0
     skipped: int = 0                 # running count (never truncated)
-    out_of_order_arrivals: int = 0
-    max_queue_depth: int = 0
+    # Seconds frames waited here: per written frame, write time minus
+    # ``put`` time on ``time.perf_counter`` (never the deadline clock).
+    # A frame that waits on a missing predecessor accrues that wait.
+    queue_s: float = 0.0
     # Only the most recent ``Monitor.max_skipped_ids`` ids are kept — a
     # lossy long-running stream skips unboundedly, the full history is the
     # count above, the tail is what an operator actually pages through.
@@ -69,13 +71,10 @@ class Monitor:
             del ids[:len(ids) - self.max_skipped_ids]
 
     def put(self, frame_id: int, payload: Any) -> None:
+        t_put = time.perf_counter()
         with self._lock:
             if frame_id >= self._next:
-                heapq.heappush(self._heap, (frame_id, payload))
-                if frame_id > self._next:
-                    self.stats.out_of_order_arrivals += 1
-                self.stats.max_queue_depth = max(self.stats.max_queue_depth,
-                                                 len(self._heap))
+                heapq.heappush(self._heap, (frame_id, t_put, payload))
             # Late arrival for an already skipped/emitted id is dropped.
             self._lock.notify_all()
 
@@ -88,7 +87,8 @@ class Monitor:
 
     def _emit_ready_locked(self) -> None:
         while self._heap and self._heap[0][0] == self._next:
-            fid, payload = heapq.heappop(self._heap)
+            fid, t_put, payload = heapq.heappop(self._heap)
+            self.stats.queue_s += time.perf_counter() - t_put
             self._write(fid, payload)
             self.stats.emitted += 1
             self._next = fid + 1

@@ -77,8 +77,9 @@ import numpy as np
 from repro.core.normalize import (AtmoState, get_lane_state,
                                   init_atmo_state_lanes, set_lane_state,
                                   unpack_atmo_states)
-from repro.stream.iobuf import fetch_valid, is_overlap_step
+from repro.stream.iobuf import fetch_ready, fetch_valid, is_overlap_step
 from repro.stream.monitor import DEADLINE_CLOCK, Monitor
+from repro.stream.spans import MONITOR_QUEUE_KEY, Phases
 from repro.stream.spout import FrameBatch, Spout
 from repro.stream.state import StreamStateStore
 
@@ -204,10 +205,11 @@ class ServeReport:
     # slices on the overlapped path; whole batches, padding included, on
     # the blocking path — the bench rows report the ratio).
     d2h_bytes: int = 0
-    # Per-phase serve-loop seconds on the scheduler's injectable clock:
-    # "host_stage_s" (lane H2D staging / batch assembly), "device_step_s"
-    # (step dispatch + simulated device time), "deliver_s" (completion
-    # threads' D2H + monitor delivery, summed across threads).
+    # Serve-loop seconds by phase (``repro.stream.spans``: one key per
+    # ``repro.<name>`` profiler span, on ``time.perf_counter``): serve
+    # thread "spout_s", "host_stage_s", "inflight_wait_s", "dispatch_s";
+    # completion threads "device_wait_s", "fetch_s" (summed across
+    # threads); and "monitor_queue_s", the monitors' per-frame wait.
     phases: Dict[str, float] = dataclasses.field(default_factory=dict)
 
     @property
@@ -333,7 +335,7 @@ class MultiStreamScheduler:
         mon_thread.start()
         raw_it = iter(req.frames)
         spout = Spout(raw_it, batch=self.batch, start_frame=start,
-                      stream_id=sid)
+                      stream_id=sid, phases=self._phases)
         self._lanes[lane_idx] = _Lane(req, raw_it, iter(spout), monitor,
                                       mon_thread, start, time.perf_counter())
         self._admissions += 1
@@ -373,6 +375,7 @@ class MultiStreamScheduler:
             lane.monitor.close()
             lane.mon_thread.join(timeout=5.0)
             lane.monitor.drain()
+            self._phases.add(MONITOR_QUEUE_KEY, lane.monitor.stats.queue_s)
             self.store.update(lane.stream_id, jax.device_get(final_state),
                               cursor)
             with self._report_lock:
@@ -547,8 +550,7 @@ class MultiStreamScheduler:
         self._overlap_ticks = 0
         self._stragglers = 0
         self._d2h_bytes = 0
-        self._phases: Dict[str, float] = {
-            "host_stage_s": 0.0, "device_step_s": 0.0, "deliver_s": 0.0}
+        self._phases = Phases(self._report_lock)
 
         packed = init_atmo_state_lanes(self.n_lanes)
         pad_frames: Optional[np.ndarray] = None       # (B, H, W, 3) zeros
@@ -594,7 +596,7 @@ class MultiStreamScheduler:
             overlap_ticks=self._overlap_ticks,
             stragglers=self._stragglers,
             d2h_bytes=self._d2h_bytes,
-            phases=dict(self._phases))
+            phases=self._phases.snapshot())
 
     def _tick_loop(self, packed: AtmoState, pad_frames: Optional[np.ndarray],
                    pad_ids: np.ndarray, sink: Optional[MultiSink]) -> int:
@@ -629,24 +631,24 @@ class MultiStreamScheduler:
                         " scheduler's frame batch")
 
             overlap = is_overlap_step(self._step)
-            t_stage = self._clock()
-            if overlap:
-                # Zero-copy path: upload only the live lanes into the
-                # persistent device buffer (padding lanes keep stale rows
-                # — id-masked from the EMA, never fetched). device_put +
-                # the donated splice dispatch asynchronously, so this H2D
-                # overlaps the in-flight tick's compute — which is why it
-                # runs BEFORE the in-flight window acquire below.
-                for i, fb in enumerate(fbs):
-                    if fb is not None:
-                        self._step.stage(i, fb.frames)
-                frames = None
-            else:
-                frames = np.stack([fb.frames if fb is not None else
-                                   pad_frames for fb in fbs])
-            ids = np.stack([fb.frame_ids if fb is not None else pad_ids
-                            for fb in fbs])
-            self._phases["host_stage_s"] += self._clock() - t_stage
+            with self._phases.span("stage"):
+                if overlap:
+                    # Zero-copy path: upload only the live lanes into the
+                    # persistent device buffer (padding lanes keep stale
+                    # rows — id-masked from the EMA, never fetched).
+                    # device_put + the donated splice dispatch
+                    # asynchronously, so this H2D overlaps the in-flight
+                    # tick's compute — which is why it runs BEFORE the
+                    # in-flight window acquire below.
+                    for i, fb in enumerate(fbs):
+                        if fb is not None:
+                            self._step.stage(i, fb.frames)
+                    frames = None
+                else:
+                    frames = np.stack([fb.frames if fb is not None else
+                                       pad_frames for fb in fbs])
+                ids = np.stack([fb.frame_ids if fb is not None else pad_ids
+                                for fb in fbs])
             metas = [(i, self._lanes[i].monitor, fb.frame_ids, fb.n_valid)
                      for i, fb in enumerate(fbs) if fb is not None]
             for i, fb in enumerate(fbs):
@@ -654,21 +656,23 @@ class MultiStreamScheduler:
                     self._lanes[i].frames_done += fb.n_valid
                     self._lanes[i].ticks += 1
 
-            self._sem.acquire()
-            t_step = self._clock()
+            with self._phases.span("inflight_wait"):
+                self._sem.acquire()
+            with self._phases.span("dispatch"):
+                if overlap:
+                    # The state input is donated into this call: every
+                    # read of `packed` (eviction snapshots, rung repacks)
+                    # was dispatched before it, and nothing touches it
+                    # after.
+                    out = self._step.tick(ids, packed)
+                else:
+                    out = self._step(frames, ids, packed)
             if overlap:
-                # The state input is donated into this call: every read
-                # of `packed` (eviction snapshots, rung repacks) was
-                # dispatched before it, and nothing touches it after.
-                out = self._step.tick(ids, packed)
                 self._overlap_ticks += 1
-            else:
-                out = self._step(frames, ids, packed)
             packed = out.state          # device-resident, possibly in flight
             self._packed = packed
             if self._tick_delay_s > 0.0:
                 time.sleep(self._tick_delay_s)
-            self._phases["device_step_s"] += self._clock() - t_step
             th = threading.Thread(target=self._complete,
                                   args=(metas, out, overlap), daemon=True)
             th.start()
@@ -684,27 +688,26 @@ class MultiStreamScheduler:
 
     def _complete(self, metas, out, overlap: bool = False) -> None:
         try:
-            t0 = self._clock()
             d2h = 0
             if overlap:
                 # Valid-only D2H: per live lane, slice on device and fetch
                 # just its real frames — padding lanes (and the padded
-                # tail of live ones) never cross the wire.
+                # tail of live ones) never cross the wire. Lane k's slice
+                # is dispatched only after lane k-1's fetch returns.
                 for lane_idx, monitor, frame_ids, n_valid in metas:
                     lane_frames = fetch_valid(out.frames, n_valid,
-                                              lane=lane_idx)
+                                              lane=lane_idx,
+                                              phases=self._phases)
                     d2h += lane_frames.nbytes
                     for b in range(n_valid):
                         monitor.put(int(frame_ids[b]), lane_frames[b])
             else:
-                frames = np.asarray(out.frames)  # blocks until device done
+                frames = fetch_ready(out.frames, self._phases)
                 d2h += frames.nbytes
                 for lane_idx, monitor, frame_ids, n_valid in metas:
                     for b in range(n_valid):
                         monitor.put(int(frame_ids[b]), frames[lane_idx, b])
-            dt = self._clock() - t0
             with self._report_lock:
                 self._d2h_bytes += d2h
-                self._phases["deliver_s"] += dt
         finally:
             self._sem.release()

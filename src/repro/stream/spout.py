@@ -6,6 +6,9 @@ from typing import Iterator, Optional
 
 import numpy as np
 
+from repro.stream.spans import Phases
+
+
 def _wire_frame(f) -> np.ndarray:
     """Keep native wire dtypes (README §Dtype contract) — uint8 is the
     round(v*255) quantized [0,1] image (4x less wire + HBM traffic than
@@ -39,14 +42,20 @@ class Spout:
     scans mask them out — they must NOT get the future real ids the spout
     will later assign to real frames (that double-advanced the coherence
     state on duplicate frames).
+
+    Batch assembly is the ``spout`` span of ``phases`` (the serve's
+    :class:`~repro.stream.spans.Phases`); waiting on the source iterator
+    is not part of it.
     """
 
     def __init__(self, frames: Iterator[np.ndarray], batch: int,
-                 start_frame: int = 0, stream_id: str = "default"):
+                 start_frame: int = 0, stream_id: str = "default",
+                 phases: Optional[Phases] = None):
         self._it = iter(frames)
         self._batch = batch
         self._next_id = start_frame
         self._stream_id = stream_id
+        self._phases = phases if phases is not None else Phases()
 
     def __iter__(self) -> Iterator[FrameBatch]:
         buf = []
@@ -59,12 +68,13 @@ class Spout:
             yield self._emit(buf)
 
     def _emit(self, buf) -> FrameBatch:
-        n_valid = len(buf)
-        while len(buf) < self._batch:
-            buf.append(buf[-1])
-        ids = np.full((self._batch,), -1, np.int32)
-        ids[:n_valid] = np.arange(self._next_id, self._next_id + n_valid,
-                                  dtype=np.int32)
-        self._next_id += n_valid
-        return FrameBatch(frames=np.stack(buf), frame_ids=ids,
-                          n_valid=n_valid, stream_id=self._stream_id)
+        with self._phases.span("spout"):
+            n_valid = len(buf)
+            while len(buf) < self._batch:
+                buf.append(buf[-1])
+            ids = np.full((self._batch,), -1, np.int32)
+            ids[:n_valid] = np.arange(self._next_id, self._next_id + n_valid,
+                                      dtype=np.int32)
+            self._next_id += n_valid
+            return FrameBatch(frames=np.stack(buf), frame_ids=ids,
+                              n_valid=n_valid, stream_id=self._stream_id)

@@ -297,14 +297,18 @@ def test_env_knob_forces_overlap(monkeypatch):
 
 
 def test_serve_report_phases_and_stragglers():
-    """Healthy serve: the three tick phases are populated on the report's
-    injectable clock and no shutdown stragglers are counted."""
+    """Healthy serve: every span key of ``repro.stream.spans`` and the
+    monitor-queue counter are on the report, the step's dispatch and the
+    fetch took time, and no shutdown stragglers are counted."""
     cfg = _cfg()
     rng = np.random.default_rng(11)
     vids = [[rng.random((12, 16, 3)).astype(np.float32) for _ in range(5)]]
     srv = ElasticServer(cfg, batch=4, timeout_s=5.0)
     rep = srv.serve_many([StreamRequest("s0", iter(vids[0]))], n_lanes=1)
-    assert set(rep.phases) == {"host_stage_s", "device_step_s", "deliver_s"}
+    assert set(rep.phases) == {"spout_s", "host_stage_s", "inflight_wait_s",
+                               "dispatch_s", "device_wait_s", "fetch_s",
+                               "monitor_queue_s"}
     assert all(v >= 0.0 for v in rep.phases.values())
-    assert rep.phases["device_step_s"] > 0.0
+    assert rep.phases["dispatch_s"] > 0.0
+    assert rep.phases["fetch_s"] > 0.0
     assert rep.stragglers == 0
