@@ -322,22 +322,6 @@ def make_multi_stream_step(cfg: DehazeConfig, associative: bool = True,
 # Sharded step (production mesh)
 # ---------------------------------------------------------------------------
 
-def _local_topk_candidates(t_raw: jnp.ndarray, frames: jnp.ndarray,
-                           k: int):
-    """Per-frame shard-local top-k smallest-t candidates over the core
-    block: ``(tk_t (B, k), tk_rgb (B, k, 3), tk_idx (B, k) int32)`` in
-    ascending (t, local flat index) order — the identical selection (and
-    tie-breaking) to ``kernels.ref.atmospheric_light``."""
-    b_loc = frames.shape[0]
-    flat_t = t_raw.reshape(b_loc, -1).astype(jnp.float32)
-    _, idx = lax.top_k(-flat_t, k)                 # k smallest, ties by idx
-    tk_t = jnp.take_along_axis(flat_t, idx, axis=-1)
-    tk_rgb = jnp.take_along_axis(
-        frames.astype(jnp.float32).reshape(b_loc, -1, 3), idx[..., None],
-        axis=1)
-    return tk_t, tk_rgb, idx.astype(jnp.int32)
-
-
 def _merge_topk_over_spatial(tk_t: jnp.ndarray, tk_rgb: jnp.ndarray,
                              tk_gidx: jnp.ndarray, axis_names, cfg):
     """Merge per-shard top-k candidate lists into the per-frame global A
@@ -484,7 +468,6 @@ def _make_sharded_step(cfg: DehazeConfig, mesh: jax.sharding.Mesh,
             guide_ext = alg.luminance(frames)
 
         # --- Component 1 on the halo-extended block (masked filters). ---
-        from repro.kernels import ref as kref
         t_raw_ext = kref.tmap_from_dark(
             spatial.masked_min_filter_2d(pre_ext, valid_h, cfg.patch_radius,
                                          valid_w),
@@ -498,7 +481,8 @@ def _make_sharded_step(cfg: DehazeConfig, mesh: jax.sharding.Mesh,
         t_raw = t_raw_ext[:, core_h, core_w]
 
         # --- Component 2: per-frame candidates (paper Eq. 5/6). ---
-        tk_t, tk_rgb, tk_idx = _local_topk_candidates(t_raw, frames, cfg.topk)
+        tk_t, tk_rgb, tk_idx = kref.smallest_t_candidates(
+            t_raw.astype(jnp.float32), frames.astype(jnp.float32), cfg.topk)
         rgb = candidates_from_local_topk(tk_t, tk_rgb, tk_idx, frames)
 
         # --- Refinement on the halo-extended block. ---

@@ -5,7 +5,9 @@ Modules:
   boxfilter     — running-sum separable box filter (guided-filter core)
   recover       — fused haze-free recovery epilogue (Eq. 8)
   atmolight     — argmin-t / robust top-k atmospheric light reduction
-                  (Eq. 5/6) + the shared in-VMEM top-k running selection
+                  (Eq. 5/6) + the shared in-VMEM top-k running selection;
+                  the XLA form (``ref``) is a first-index argmin reduction
+                  at k = 1 and ``lax.top_k`` at k > 1
   fused         — single-pass DCP/CAP megakernels (Eq. 3/4+5/6+9+8 in one
                   launch), incl. the halo-aware variant for height- and/or
                   width-sharded meshes (2-D validity masking)

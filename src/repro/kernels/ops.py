@@ -179,9 +179,12 @@ def atmospheric_light(img: jnp.ndarray, t_raw: jnp.ndarray, k: int = 1,
                       mode: Mode = "auto") -> jnp.ndarray:
     """(..., H, W, 3), (..., H, W) -> (..., 3).
 
-    k=1 is the Eq. 6 argmin-t reduction; k>1 the robust mean-of-top-k
-    (``atmolight_topk_pallas``, an in-VMEM k-row running selection). Both
-    match ``kernels.ref.atmospheric_light`` including tie-breaking.
+    k=1 is the Eq. 6 argmin-t pixel; k>1 the robust mean-of-top-k. On the
+    XLA substrate (``ref``, what ``auto`` resolves to) k=1 is a first-index
+    argmin reduction and k>1 a ``lax.top_k``; the Pallas kernels are
+    ``atmolight_pallas`` and ``atmolight_topk_pallas`` (an in-VMEM k-row
+    running selection). All break ties at the lowest flat index, as
+    ``kernels.ref.atmospheric_light`` does.
     """
     m = resolve_mode(mode)
     if m == "ref":

@@ -152,18 +152,50 @@ def guided_filter(guide: jnp.ndarray, src: jnp.ndarray, radius: int,
 # Atmospheric light estimation (paper Eq. 5/6, robust top-k form)
 # ---------------------------------------------------------------------------
 
+def first_min_index(t: jnp.ndarray) -> jnp.ndarray:
+    """(..., H, W) -> (...,) int32: the row-major flat index of each
+    plane's first minimum, Eq. 6's pixel.
+
+    One argmin reduction, ties at the lowest flat index: the pixel
+    ``lax.top_k(-t, 1)`` picks, without sorting the plane.
+    """
+    flat = t.reshape(*t.shape[:-2], -1)
+    return jnp.argmin(flat, axis=-1).astype(jnp.int32)
+
+
 def atmospheric_light(img: jnp.ndarray, t_raw: jnp.ndarray, k: int = 1) -> jnp.ndarray:
     """A = mean of I over the k pixels with smallest raw transmission.
 
-    k=1 reproduces paper Eq. 6 exactly (the argmin-t pixel). Larger k is
-    the standard robustification (top 0.1 %). ``img``: (..., H, W, 3),
+    k=1 reproduces paper Eq. 6 exactly (the argmin-t pixel) as a
+    first-index argmin reduction (:func:`first_min_index`). Larger k is
+    the standard robustification (top 0.1 %), a ``lax.top_k`` selection;
+    both break ties at the lowest flat index. ``img``: (..., H, W, 3),
     ``t_raw``: (..., H, W) -> (..., 3).
     """
-    flat_t = t_raw.reshape(*t_raw.shape[:-2], -1)
     flat_i = img.reshape(*img.shape[:-3], -1, 3)
+    if k == 1:
+        j = first_min_index(t_raw)
+        return jnp.take_along_axis(flat_i, j[..., None, None], axis=-2)[..., 0, :]
+    flat_t = t_raw.reshape(*t_raw.shape[:-2], -1)
     _, idx = lax.top_k(-flat_t, k)                      # smallest t
     picked = jnp.take_along_axis(flat_i, idx[..., None], axis=-2)
     return picked.mean(axis=-2)
+
+
+def smallest_t_candidates(t_raw: jnp.ndarray, x: jnp.ndarray, k: int):
+    """Per-frame k smallest-t candidates of a (B, H, W) block: ``(tk_t
+    (B, k), tk_rgb (B, k, 3), tk_idx (B, k) int32)`` in ascending (t, flat
+    index) order, the selection :func:`atmospheric_light` makes. ``k == 1``
+    is :func:`first_min_index`; ``k > 1`` is ``lax.top_k``."""
+    b = t_raw.shape[0]
+    flat_t = t_raw.reshape(b, -1)
+    if k == 1:
+        idx = first_min_index(t_raw)[:, None]
+    else:
+        _, idx = lax.top_k(-flat_t, k)             # k smallest, ties by idx
+    tk_t = jnp.take_along_axis(flat_t, idx, axis=-1)
+    tk_rgb = jnp.take_along_axis(x.reshape(b, -1, 3), idx[..., None], axis=1)
+    return tk_t, tk_rgb, idx.astype(jnp.int32)
 
 
 # ---------------------------------------------------------------------------
@@ -253,20 +285,14 @@ def fused_transmission(img: jnp.ndarray, A_saved: jnp.ndarray, *,
     cast to :func:`resolve_out_dtype`.
     """
     odt = resolve_out_dtype(img.dtype, out_dtype)
-    b = img.shape[0]
     x = upcast_frames(img)
     a0 = jnp.maximum(A_saved.astype(jnp.float32), 1e-3)
     pre = premap(x, a0, algorithm, cap_w)
     dark = min_filter_2d(pre, radius)
     t_raw = tmap_from_dark(dark, algorithm, omega, beta)
-    flat_t = t_raw.reshape(b, -1)
-    j = jnp.argmin(flat_t, axis=-1)
-    t_min = jnp.take_along_axis(flat_t, j[:, None], axis=-1)[:, 0]
-    if topk == 1:
-        cand = jnp.take_along_axis(x.reshape(b, -1, 3), j[:, None, None],
-                                   axis=1)[:, 0]
-    else:
-        cand = atmospheric_light(x, t_raw, topk)
+    tk_t, tk_rgb, _ = smallest_t_candidates(t_raw, x, 1)
+    t_min = tk_t[:, 0]
+    cand = tk_rgb[:, 0] if topk == 1 else atmospheric_light(x, t_raw, topk)
     if refine:
         t = jnp.clip(guided_filter(luminance(x), t_raw, gf_radius, gf_eps),
                      0.0, 1.0)
@@ -310,7 +336,7 @@ def fused_transmission_halo(img: jnp.ndarray, pre_ext: jnp.ndarray,
     """
     from repro.core import spatial                 # lazy: spatial imports ref
     odt = resolve_out_dtype(img.dtype, out_dtype)
-    b, h_loc, w_loc = img.shape[0], img.shape[1], img.shape[2]
+    h_loc, w_loc = img.shape[1], img.shape[2]
     halo_h = (pre_ext.shape[1] - h_loc) // 2
     halo_w = (pre_ext.shape[2] - w_loc) // 2
     dark = spatial.masked_min_filter_2d(pre_ext.astype(jnp.float32), valid,
@@ -326,13 +352,9 @@ def fused_transmission_halo(img: jnp.ndarray, pre_ext: jnp.ndarray,
         t = jnp.clip(t_ext[:, core_h, core_w], 0.0, 1.0)
     else:
         t = t_raw
-    flat_t = t_raw.reshape(b, -1)
-    _, idx = lax.top_k(-flat_t, topk)              # k smallest, ties by idx
-    tk_t = jnp.take_along_axis(flat_t, idx, axis=-1)
-    tk_rgb = jnp.take_along_axis(upcast_frames(img).reshape(b, -1, 3),
-                                 idx[..., None], axis=1)
-    return (t.astype(odt), tk_t, tk_rgb.astype(odt),
-            idx.astype(jnp.int32))
+    tk_t, tk_rgb, idx = smallest_t_candidates(t_raw, upcast_frames(img),
+                                              topk)
+    return t.astype(odt), tk_t, tk_rgb.astype(odt), idx
 
 
 def fused_dehaze(img: jnp.ndarray, frame_ids: jnp.ndarray,

@@ -59,19 +59,35 @@ def _compile(step, frames_shape, ids_shape, state, sharding):
         state_spec).compile()
 
 
-@pytest.mark.parametrize("lanes", [0, 2], ids=["single", "lanes2"])
-def test_serving_step_compiles_for_v5e(one_chip, lanes):
-    cfg = DehazeConfig(algorithm="dcp", io_dtype="uint8")   # mode "auto"
+def _serving_step(algorithm, batch, lanes, sharding):
+    """The auto-mode uint8 serving step at 1080p, compiled for one chip."""
+    cfg = DehazeConfig(algorithm=algorithm, io_dtype="uint8")  # mode "auto"
     if lanes:
         step = make_step(cfg, PlacementSpec.lane_batched(), donate="state")
-        compiled = _compile(step, (lanes, B, H, W, 3), (lanes, B),
-                            init_atmo_state_lanes(lanes), one_chip)
-    else:
-        step = make_step(cfg, PlacementSpec.single(), donate=True)
-        compiled = _compile(step, (B, H, W, 3), (B,), init_atmo_state(),
-                            one_chip)
+        return _compile(step, (lanes, batch, H, W, 3), (lanes, batch),
+                        init_atmo_state_lanes(lanes), sharding)
+    step = make_step(cfg, PlacementSpec.single(), donate=True)
+    return _compile(step, (batch, H, W, 3), (batch,), init_atmo_state(),
+                    sharding)
+
+
+@pytest.mark.parametrize("lanes", [0, 2], ids=["single", "lanes2"])
+def test_serving_step_compiles_for_v5e(one_chip, lanes):
+    compiled = _serving_step("dcp", B, lanes, one_chip)
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
     assert total < HBM_BYTES, f"{total / 1e9:.2f} GB does not fit one chip"
     assert "tpu_custom_call" not in compiled.as_text()
+
+
+@pytest.mark.parametrize("lanes", [0, 2], ids=["single", "lanes2"])
+@pytest.mark.parametrize("algorithm,batch", [("dcp", B), ("cap", 1)],
+                         ids=["dcp-b8", "cap-b1"])
+def test_serving_step_picks_light_without_sort(one_chip, algorithm, batch,
+                                               lanes):
+    """At ``topk=1`` Eq. 6's pixel is an argmin reduction: the step holds
+    no ``TopK`` custom call and no sort of the 2,073,600-pixel plane."""
+    text = _serving_step(algorithm, batch, lanes, one_chip).as_text()
+    assert "TopK" not in text
+    assert "sort(" not in text

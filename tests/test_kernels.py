@@ -82,6 +82,44 @@ def test_atmolight_tiled_grid():
     np.testing.assert_allclose(got, want, atol=1e-6)
 
 
+def _tie_map(case):
+    """A quantized transmission map with a tie pattern at its minimum."""
+    lead = {"batch_ties": (4,), "lanes": (3, 2)}.get(case, (2,))
+    shape = lead + (6, 16)
+    t = np.round(np.random.default_rng(7).uniform(0.25, 1.0, shape) * 8) / 8
+    if case == "constant":
+        t[:] = 0.5
+    elif case == "row_plateau":                   # rows 2-4, and row 3 col 0
+        t[..., 2:5, 5:] = 0.125
+        t[..., 3, 0] = 0.125
+    elif case == "last_pixel":
+        t[..., -1, -1] = 0.0
+    elif case == "batch_ties":                    # one min value, several
+        t[0, 1, 9] = t[1, 4, 2] = t[2, 0, 15] = 0.125     # places per frame
+        t[2, 5, 0] = t[3, 3, 3] = t[3, 3, 4] = 0.125
+    elif case == "lanes":
+        t[..., 1:, 7] = 0.125
+        t[1, 0, 0, 15] = t[2, 1, 5, 0] = 0.125
+    return jnp.asarray(t, jnp.float32)
+
+
+@pytest.mark.parametrize("case", ["constant", "row_plateau", "last_pixel",
+                                  "batch_ties", "lanes"])
+def test_atmolight_k1_ties_match_topk(case):
+    """k=1 is a first-index argmin reduction: it picks exactly the pixel
+    ``lax.top_k(-t, 1)`` picks, ties included, over any leading dims."""
+    t = _tie_map(case)
+    img = _img(t.shape, jnp.float32, seed=3)
+    flat_t = t.reshape(*t.shape[:-2], -1)
+    _, idx = jax.lax.top_k(-flat_t, 1)
+    want = jnp.take_along_axis(img.reshape(*t.shape[:-2], -1, 3),
+                               idx[..., None], axis=-2).mean(axis=-2)
+    np.testing.assert_array_equal(np.asarray(ref.first_min_index(t)),
+                                  np.asarray(idx[..., 0]))
+    np.testing.assert_array_equal(np.asarray(ref.atmospheric_light(img, t)),
+                                  np.asarray(want))
+
+
 @pytest.mark.parametrize("shape", SHAPES)
 @pytest.mark.parametrize("dtype", DTYPES)
 @pytest.mark.parametrize("gamma", [1.0, 2.2])
